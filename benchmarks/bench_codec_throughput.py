@@ -20,11 +20,14 @@ Compares the paths that exist in the system:
                     deliveries coalesced into a ``BlockReceiveRing`` arena,
                     decoded as borrowed views of the ring's own memory
   * pallas_f16    — the quantize_f16 kernel path emitting owned ``bytes``
-                    (interpret mode on CPU; on TPU this is the compiled
-                    VMEM-tiled kernel)
   * pallas_f16_vec — the same kernel handing the wire a borrowed view,
                     spliced into a vectored message (no ``bytes`` handoff)
   * q8_kernel     — blockwise int8 compression kernel
+
+The three kernel rows are host timings of the Pallas *interpreter* when
+this runs on the CPU (as every committed ``BENCH_codec.json`` row was):
+they time XLA's CPU backend running the kernel body, not the kernel on a
+TPU, and are no device number.
 
 ``run()`` prints the CSV section; ``run_json()`` additionally returns the
 machine-readable record (encode/decode MB/s, tracemalloc peak bytes, and a
@@ -150,8 +153,14 @@ def run_json() -> tuple[list[str], dict]:
     """-> (CSV rows, BENCH_codec.json record)."""
     import jax.numpy as jnp
 
+    from repro.kernels import interpret_mode
+
     rows = ["path,model_size,us_per_call,derived_MBps"]
-    record: dict = {"bench": "codec_throughput", "unit": "MB/s", "sizes": {}}
+    record: dict = {"bench": "codec_throughput", "unit": "MB/s", "sizes": {},
+                    "kernel_rows": ("host timing of the Pallas interpreter, "
+                                    "not a device number"
+                                    if interpret_mode() else
+                                    "compiled Pallas kernels, host clock")}
     rng = np.random.default_rng(0)
     for n in SIZES:
         flat = rng.standard_normal(n).astype(np.float32)

@@ -166,9 +166,6 @@ def main() -> int:
         print(f"## {name} ({dt:.1f}s)")
         print("\n".join(rows))
         print()
-    print("## roofline")
-    print("see reports/roofline.json + EXPERIMENTS.md §Roofline "
-          "(derived from the dry-run artifacts, not wall-clock)")
     return 0
 
 

@@ -10,10 +10,11 @@ checkpointing with restart.
 """
 import argparse
 import tempfile
+from pathlib import Path
 
 import jax
-import numpy as np
 
+from repro import compile_cache
 from repro.core.messages import ParamsEncoding
 from repro.core.params_codec import flatten_params
 from repro.data import partition_dirichlet, synthetic_mnist
@@ -33,6 +34,7 @@ def main() -> None:
                     choices=[e.value for e in ParamsEncoding])
     ap.add_argument("--non-iid-alpha", type=float, default=1.0)
     args = ap.parse_args()
+    compile_cache.enable(Path(__file__).resolve().parents[1])
 
     params = lenet5.init_params(jax.random.PRNGKey(0))
     flat, spec = flatten_params(params)

@@ -13,23 +13,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.q8_block.q8_block import BLOCK, dequantize_q8, quantize_q8
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def _quantize_blocks(flat: jax.Array):
     n = flat.shape[0]
     pad = (-n) % BLOCK
     blocks = jnp.pad(flat, (0, pad)).reshape(-1, BLOCK)
-    return quantize_q8(blocks, interpret=not _ON_TPU)
+    return quantize_q8(blocks, interpret=interpret_mode())
 
 
 def compress_update(flat: jax.Array):
     """f32 vector -> (int8 values, f32 scales, reconstruction error)."""
     n = flat.shape[0]
     q, scales = _quantize_blocks(flat)
-    deq = dequantize_q8(q, scales, interpret=not _ON_TPU).reshape(-1)[:n]
+    deq = dequantize_q8(q, scales, interpret=interpret_mode()).reshape(-1)[:n]
     return q.reshape(-1)[:n], scales, flat - deq
 
 
@@ -78,7 +77,7 @@ def q8_chunk_arrays(flat):
         return (np.empty(0, np.int8), np.empty(0, "<f4"),
                 np.empty(0, np.float32))
     q, scales = _quantize_blocks(jnp.asarray(flat_np))
-    deq = dequantize_q8(q, scales, interpret=not _ON_TPU).reshape(-1)[:n]
+    deq = dequantize_q8(q, scales, interpret=interpret_mode()).reshape(-1)[:n]
     q_np = np.ascontiguousarray(np.asarray(q).reshape(-1))
     s_np = np.ascontiguousarray(np.asarray(scales)).astype("<f4", copy=False)
     return q_np, s_np, flat_np - np.asarray(deq)
@@ -87,5 +86,5 @@ def q8_chunk_arrays(flat):
 def decompress_update(q: np.ndarray, scales: np.ndarray, n: int) -> np.ndarray:
     pad = (-n) % BLOCK
     qb = jnp.pad(jnp.asarray(q), (0, pad)).reshape(-1, BLOCK)
-    out = dequantize_q8(qb, jnp.asarray(scales), interpret=not _ON_TPU)
+    out = dequantize_q8(qb, jnp.asarray(scales), interpret=interpret_mode())
     return np.asarray(out.reshape(-1)[:n])

@@ -17,15 +17,14 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.quantize_f16.quantize_f16 import dequantize_f16, quantize_f16
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def _f16_bits(flat: jax.Array) -> np.ndarray:
     """Kernel output as a host little-endian u2 array (no copy on LE hosts;
     on CPU ``np.asarray`` aliases the device buffer)."""
-    bits = quantize_f16(flat, interpret=not _ON_TPU)
+    bits = quantize_f16(flat, interpret=interpret_mode())
     return np.ascontiguousarray(np.asarray(bits)).astype("<u2", copy=False)
 
 
@@ -73,5 +72,5 @@ def params_to_f16_payload(flat: jax.Array) -> bytes:
 
 def f16_payload_to_params(payload) -> np.ndarray:
     bits = np.frombuffer(payload, dtype="<u2")
-    out = dequantize_f16(jax.numpy.asarray(bits), interpret=not _ON_TPU)
+    out = dequantize_f16(jax.numpy.asarray(bits), interpret=interpret_mode())
     return np.asarray(out)

@@ -11,16 +11,9 @@ import jax
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions: ``AxisType`` and the ``axis_types``
-    kwarg only exist on newer releases; older ones get the positional form."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

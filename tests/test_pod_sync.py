@@ -33,15 +33,9 @@ grads = {"w": jnp.asarray(rng.standard_normal((2, 512, 8)) * 0.01,
 def sync(g):
     return _q8_pod_sync(g, axis="pod")
 
-if hasattr(jax, "shard_map"):    # jax >= 0.6: top-level API, vma checking
-    smap = jax.shard_map(sync, mesh=mesh, in_specs=(P("pod"),),
-                         out_specs=P("pod"),
-                         axis_names=frozenset({"pod", "data"}),
-                         check_vma=False)
-else:                            # older jax: experimental API, check_rep
-    from jax.experimental.shard_map import shard_map
-    smap = shard_map(sync, mesh=mesh, in_specs=(P("pod"),),
-                     out_specs=P("pod"), check_rep=False)
+smap = jax.shard_map(sync, mesh=mesh, in_specs=(P("pod"),),
+                     out_specs=P("pod"), axis_names=frozenset({"pod", "data"}),
+                     check_vma=False)
 synced = jax.jit(smap)(grads)
 
 for k in grads:
