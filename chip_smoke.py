@@ -81,30 +81,6 @@ def emit(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
 
-class CompileMonitor:
-    """Backend compile seconds and persistent-cache hits/writes, from
-    JAX's monitoring events."""
-
-    def __init__(self) -> None:
-        self.compile_s = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        self.cache_writes = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-            self.compiles += 1
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_writes += 1     # JAX records a miss as it writes
-
-
 def phase_fl_rounds(seed: int, platform: str) -> dict:
     from repro.core.params_codec import flatten_params
     from repro.data import partition_iid, synthetic_mnist
@@ -270,12 +246,12 @@ def main() -> int:
         return 2
     sys.path.insert(0, str(SRC))
     os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no logs in /tmp
-    from repro import compile_cache
+    from repro import compile_cache, obs
 
     cache_dir = compile_cache.enable(ROOT)
     entries_before = (sum(1 for _ in cache_dir.iterdir())
                       if cache_dir.is_dir() else 0)
-    monitor = CompileMonitor()
+    before = obs.compile_totals()
 
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -296,11 +272,13 @@ def main() -> int:
         emit(phase=name, ok=True, **summary,
              smoke_wall_s=time.perf_counter() - t0)
 
+    after = obs.compile_totals()
+    got = {k: after[k] - before[k] for k in after}
     emit(phase="compile", cache_dir=str(cache_dir),
          cache_entries_before=entries_before,
-         cache_hits=monitor.cache_hits, cache_writes=monitor.cache_writes,
-         cache_warm=monitor.cache_hits > 0 and monitor.cache_writes == 0,
-         backend_compiles=monitor.compiles, compile_s=monitor.compile_s,
+         cache_hits=got["cache_hits"], cache_writes=got["cache_writes"],
+         cache_warm=got["cache_hits"] > 0 and got["cache_writes"] == 0,
+         backend_compiles=got["compiles"], compile_s=got["compile_s"],
          smoke_wall_s=time.perf_counter() - t_all)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

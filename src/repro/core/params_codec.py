@@ -21,6 +21,7 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import cbor
 from repro.core.cbor import Tag
 from repro.core.typed_arrays import (
@@ -61,6 +62,11 @@ class ParamsSpec:
 
 def flatten_params(params: Pytree) -> tuple[np.ndarray, ParamsSpec]:
     leaves, treedef = jax.tree.flatten(params)
+    on_device = [l for l in leaves if isinstance(l, jax.Array)]
+    if on_device:
+        with obs.span(obs.WAIT):
+            jax.block_until_ready(on_device)
+        obs.d2h(on_device)
     arrs = [np.asarray(l) for l in leaves]
     flat = np.concatenate([a.reshape(-1).astype(np.float32) for a in arrs])
     spec = ParamsSpec(treedef, tuple(a.shape for a in arrs),

@@ -39,6 +39,7 @@ from typing import Callable, Iterator, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core import cddl, fastpath
 from repro.core.fastpath import ScatterPayload
 from repro.core.messages import (
@@ -743,7 +744,8 @@ def run_selective_repeat(
                 msg = chunks[i]
                 for ridx, rcv in enumerate(receivers):
                     if i in delivery.delivered[ridx]:
-                        rcv.receive_chunk(msg)
+                        with obs.span(obs.ASSEMBLE):
+                            rcv.receive_chunk(msg)
         if crash_now:
             break                # the sender died mid-window: no feedback
         # NACK round-trip: every not-yet-acked receiver reports its state.
@@ -925,17 +927,10 @@ def run_medium_downlink(
                     ring.feed(fr.msg)
                     if not ring.complete:
                         continue
-                    try:
-                        msg = FLModelChunk.from_cbor_segments(
-                            ring.segments())
-                    except _CORRUPT_ERRORS:
-                        del rings[r][i]
-                        report.corrupt_chunks += 1
-                        continue
                     del rings[r][i]
-                    try:
-                        done = receivers[r].receive_chunk(msg)
-                    except _CORRUPT_ERRORS:
+                    with obs.span(obs.ASSEMBLE):
+                        ok, done = _assemble(ring, receivers[r])
+                    if not ok:
                         report.corrupt_chunks += 1
                         continue
                     delivered[r].add(i)
@@ -1145,6 +1140,18 @@ _CORRUPT_ERRORS = (ValueError, TypeError, KeyError, IndexError,
                    OverflowError, EOFError)
 
 
+def _assemble(ring: BlockReceiveRing, receiver) -> tuple[bool, bool]:
+    """Decode a completed ring's chunk and hand it to ``receiver``:
+    ``(accepted, done)``.  Not accepted when the arena did not decode or
+    the chunk failed its CRC / geometry checks; ``done`` is what
+    ``receive_chunk`` returned (the receiver's model is complete)."""
+    try:
+        msg = FLModelChunk.from_cbor_segments(ring.segments())
+        return True, receiver.receive_chunk(msg)
+    except _CORRUPT_ERRORS:
+        return False, False
+
+
 def _deliver(by_client: dict[int, UplinkSession], frame,
              on_complete) -> None:
     """Route one released frame into its session's per-chunk reorder-aware
@@ -1158,20 +1165,12 @@ def _deliver(by_client: dict[int, UplinkSession], frame,
     ring.feed(frame.msg)             # slots by Block1 NUM; dups dropped
     if not ring.complete:
         return                       # gap: wait for repair to fill it
-    try:
-        msg = FLModelChunk.from_cbor_segments(ring.segments())
-    except _CORRUPT_ERRORS:
-        del sess.rings[frame.chunk_index]   # garbage arena: drop it whole
+    del sess.rings[frame.chunk_index]   # a garbage arena is dropped whole
+    with obs.span(obs.ASSEMBLE):
+        ok, done = _assemble(ring, sess.receiver)
+    if not ok:
         sess.report.corrupt_chunks += 1
         return                       # not delivered => NACK re-requests it
-    del sess.rings[frame.chunk_index]   # arena freed once msg is consumed
-    try:
-        done = sess.receiver.receive_chunk(msg)
-    except _CORRUPT_ERRORS:
-        # decoded as CBOR but failed chunk CRC / geometry checks: same
-        # recovery as an undecodable arena
-        sess.report.corrupt_chunks += 1
-        return
     sess.delivered_chunks.add(frame.chunk_index)
     if done and not sess.assembled:
         sess.assembled = True

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.messages import (
     FLChunkAck,
     FLChunkNack,
@@ -300,26 +301,27 @@ class FLClient:
             raise RuntimeError("no local model to upload")
         if isinstance(encoding, str):
             encoding = ParamsEncoding(encoding)
-        flat, _ = flatten_params(self.params)
-        if residual:
-            if self.last_global_flat is None:
-                raise RuntimeError("no installed global model to diff "
-                                   "against for a residual uplink")
-            if self.last_global_flat.size != flat.size:
-                raise ValueError("residual reference does not match the "
-                                 "local model size")
-            flat = flat - self.last_global_flat
-        ef = None
-        if encoding in (ParamsEncoding.TA_F16, ParamsEncoding.Q8):
-            ef = self.error_feedback
-            if self._ef_round == self.round:
-                ef.residual = self._ef_prev      # same-round replay
-            else:
-                self._ef_round = self.round
-                self._ef_prev = ef.residual
-        return list(chunk_stream(self.model_id, self.round, flat,
-                                 chunk_elems, encoding=encoding,
-                                 error_feedback=ef))
+        with obs.span(obs.CLIENT_ENCODE):
+            flat, _ = flatten_params(self.params)
+            if residual:
+                if self.last_global_flat is None:
+                    raise RuntimeError("no installed global model to diff "
+                                       "against for a residual uplink")
+                if self.last_global_flat.size != flat.size:
+                    raise ValueError("residual reference does not match "
+                                     "the local model size")
+                flat = flat - self.last_global_flat
+            ef = None
+            if encoding in (ParamsEncoding.TA_F16, ParamsEncoding.Q8):
+                ef = self.error_feedback
+                if self._ef_round == self.round:
+                    ef.residual = self._ef_prev      # same-round replay
+                else:
+                    self._ef_round = self.round
+                    self._ef_prev = ef.residual
+            return list(chunk_stream(self.model_id, self.round, flat,
+                                     chunk_elems, encoding=encoding,
+                                     error_feedback=ef))
 
     def uplink_session(self, chunk_elems: int, receiver, *,
                        encoding: ParamsEncoding | str =
@@ -344,19 +346,27 @@ class FLClient:
         """Run E local epochs; returns the observe notification payload."""
         if self.params is None:
             raise RuntimeError("no global model installed")
-        rng = np.random.default_rng((self.seed, self.client_id, self.round))
-        opt_state: dict = {}
-        n = len(self._train_idx)
-        for _ in range(self.local_epochs):
-            order = rng.permutation(n)
-            for start in range(0, n - self.batch_size + 1, self.batch_size):
-                idx = self._train_idx[order[start:start + self.batch_size]]
-                batch = {k: jnp.asarray(v[idx]) for k, v in self.data.items()}
-                _, grads = self._grad_fn(self.params, batch)
-                self.params, opt_state = sgd_update(self.params, grads,
-                                                    opt_state, self.sgd)
-                self.samples_seen += self.batch_size
-        return self.progress_update()
+        with obs.span(obs.CLIENT_TRAIN):
+            # the installed global's host leaves go to the device once,
+            # here, instead of implicitly inside the first jitted step
+            obs.h2d(jax.tree.leaves(self.params))
+            self.params = jax.device_put(self.params)
+            rng = np.random.default_rng((self.seed, self.client_id,
+                                         self.round))
+            opt_state: dict = {}
+            n = len(self._train_idx)
+            for _ in range(self.local_epochs):
+                order = rng.permutation(n)
+                for start in range(0, n - self.batch_size + 1,
+                                   self.batch_size):
+                    idx = self._train_idx[order[start:start
+                                                + self.batch_size]]
+                    _, grads = self._grad_fn(self.params, self._batch(idx))
+                    self.params, opt_state = sgd_update(
+                        self.params, grads, opt_state, self.sgd)
+                    self.samples_seen += self.batch_size
+                    obs.count("train_steps", 1)
+            return self.progress_update()
 
     def progress_update(self) -> FLLocalDataSetUpdate:
         return FLLocalDataSetUpdate(
@@ -368,9 +378,18 @@ class FLClient:
         vl = self._eval(self._val_idx[:256])
         return float(tl), float(vl)
 
+    def _batch(self, idx: np.ndarray) -> dict:
+        """The rows ``idx`` of this client's data, copied to the device."""
+        host = {k: v[idx] for k, v in self.data.items()}
+        obs.h2d(host.values())
+        return {k: jnp.asarray(v) for k, v in host.items()}
+
     def _eval(self, idx: np.ndarray) -> float:
-        batch = {k: jnp.asarray(v[idx]) for k, v in self.data.items()}
-        return float(self._eval_fn(self.params, batch))
+        loss = self._eval_fn(self.params, self._batch(idx))
+        with obs.span(obs.WAIT):
+            loss.block_until_ready()
+        obs.d2h([loss])
+        return float(loss)
 
     def local_model_update(self) -> FLLocalModelUpdate:
         """GET /fl/model — reply with the locally-trained model."""

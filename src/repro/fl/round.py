@@ -40,6 +40,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import obs
 from repro.fl.aggregation import RunningFedAvg
 from repro.fl.chunking import MAX_REPAIR_WINDOWS
 from repro.fl.faults import FaultPlan
@@ -293,6 +294,10 @@ class RoundEngine:
     # -- fresh round ---------------------------------------------------------
 
     def run(self) -> RoundResult:
+        with obs.span(obs.ROUND):
+            return self._run()
+
+    def _run(self) -> RoundResult:
         sim, server = self.sim, self.sim.server
         sim.link.mark_round_start()
         self._open_round_medium()
@@ -356,6 +361,10 @@ class RoundEngine:
         heard the round close), the fold is ignored idempotently.
         Returns None when no snapshot exists for the current round.
         """
+        with obs.span(obs.ROUND):
+            return self._resume()
+
+    def _resume(self) -> RoundResult | None:
         sim = self.sim
         state = load_agg_snapshot(sim.server)
         if state is None:
@@ -435,14 +444,16 @@ class RoundEngine:
             upd = client.train_locally()
             sim._client_checkpoint(cid)   # durable trained-model state
             t0 = self.clock
-            ring = sim._send(upd.to_cbor_segments(),
-                             "FL_Local_DataSet_Update",
-                             "fl/progress", Code.CONTENT)
+            with obs.span(obs.REPORT):
+                ring = sim._send(upd.to_cbor_segments(),
+                                 "FL_Local_DataSet_Update",
+                                 "fl/progress", Code.CONTENT)
+                if ring is not None:
+                    upd = type(upd).from_cbor_segments(ring)
             if ring is None:
                 self._attr(cid, "link")
                 dropped.append(cid)   # report lost on the link
                 continue
-            upd = type(upd).from_cbor_segments(ring)
             progress[cid] = upd
             ready[cid] = (t_model
                           + policy.train_time_s * client.straggler_factor
@@ -484,12 +495,13 @@ class RoundEngine:
             # quorum re-check *at the deadline* decides
             installed = (quorum_final if deadline is not None
                          else bool(self.folded))
-            if installed:
-                server.finalize_aggregation()
-                self._snapshot(finalized=True)
-            else:
-                server.abort_aggregation()
-                clear_agg_snapshot(server)
+            with obs.span(obs.FINALIZE):
+                if installed:
+                    server.finalize_aggregation()
+                    self._snapshot(finalized=True)
+                else:
+                    server.abort_aggregation()
+                    clear_agg_snapshot(server)
         quorum_met = (installed if (reporters and quorum_pre)
                       else quorum_pre)
         if not quorum_pre:
@@ -514,14 +526,28 @@ class RoundEngine:
             snapshot_bytes=self.snapshot_bytes,
             fault_attribution=dict(sorted(self.attribution.items())),
         )
-        clear_agg_snapshot(server)      # the round is over either way
-        self.sim._round_medium = None   # the round's fault domain closes
-        server.finish_round(result)
+        self._count_frames(self.sim._round_medium)
+        with obs.span(obs.FINALIZE):
+            clear_agg_snapshot(server)  # the round is over either way
+            self.sim._round_medium = None   # the round's fault domain closes
+            server.finish_round(result)
         return result
+
+    @staticmethod
+    def _count_frames(medium) -> None:
+        """Copy a round medium's frame counts into the round's record."""
+        if medium is not None:
+            obs.count("frames_sent", medium.frames_sent)
+            obs.count("frames_lost", medium.frames_lost)
 
     # -- folding (shared by every uplink mode) -------------------------------
 
     def _fold(self, cid: int, flat: np.ndarray, dataset_size: int) -> bool:
+        with obs.span(obs.SERVER_FOLD):
+            return self._fold_one(cid, flat, dataset_size)
+
+    def _fold_one(self, cid: int, flat: np.ndarray,
+                  dataset_size: int) -> bool:
         server = self.sim.server
         if server.already_folded(cid):
             # duplicate re-fold (a resumed round re-receiving an upload
@@ -590,16 +616,19 @@ class RoundEngine:
                 continue
             if not self._deadline_gate(cid, ready):
                 continue
-            ring = sim._send(
-                sim.clients[cid].local_model_update().to_cbor_segments(enc),
-                "FL_Local_Model_Update", "fl/model", Code.CONTENT)
+            payload = sim.clients[cid].local_model_update().to_cbor_segments(
+                enc)
+            with obs.span(obs.SCHED_UPLINK):
+                ring = sim._send(payload, "FL_Local_Model_Update",
+                                 "fl/model", Code.CONTENT)
             if ring is None:
                 self._attr(cid, "link")
                 dropped.append(cid)   # model transfer lost
                 continue
             if self._missed_deadline(cid):
                 continue              # arrived after the round closed
-            upd = FLLocalModelUpdate.from_cbor_segments(ring)
+            with obs.span(obs.ASSEMBLE):
+                upd = FLLocalModelUpdate.from_cbor_segments(ring)
             if upd.round != server.round or upd.model_id != server.model_id:
                 self._attr(cid, "churn")
                 dropped.append(cid)   # stale generation
@@ -628,10 +657,11 @@ class RoundEngine:
                          and crash.resume
                          and sim.clients[cid].checkpoint_dir is not None)
             budget = None if deadline is None else deadline - self.clock
-            flat = sim._collect_chunked(
-                cid, backoff=self.policy.backoff, faults=self.faults,
-                airtime_budget_s=budget, encoding=enc, residual=residual,
-                keep_partial=resumable)
+            with obs.span(obs.SCHED_UPLINK):
+                flat = sim._collect_chunked(
+                    cid, backoff=self.policy.backoff, faults=self.faults,
+                    airtime_budget_s=budget, encoding=enc,
+                    residual=residual, keep_partial=resumable)
             if (flat is None and resumable
                     and (deadline is None or self.clock < deadline)
                     and sim.restart_client(cid)):
@@ -640,10 +670,12 @@ class RoundEngine:
                 # back on the air (strictly fewer payload bytes)
                 self._attr(cid, "crash-resumed")
                 budget = None if deadline is None else deadline - self.clock
-                flat = sim._collect_chunked(
-                    cid, backoff=self.policy.backoff, faults=self.faults,
-                    airtime_budget_s=budget, encoding=enc,
-                    residual=residual, poll_first=True, resumed=True)
+                with obs.span(obs.SCHED_UPLINK):
+                    flat = sim._collect_chunked(
+                        cid, backoff=self.policy.backoff,
+                        faults=self.faults, airtime_budget_s=budget,
+                        encoding=enc, residual=residual, poll_first=True,
+                        resumed=True)
             if flat is None:
                 if not self._missed_deadline(cid):
                     if crash is not None and crash.phase in ("upload",
@@ -664,18 +696,21 @@ class RoundEngine:
         deadline = self.policy.deadline_s
         enc, residual = self._chunk_mode()
         sessions = []
-        for cid in pending:
-            crash = self.faults.client_crash(cid)
-            kwargs = {"start_at": ready.get(cid, 0.0)}
-            if backoff is not None:
-                kwargs["max_windows"] = backoff.max_windows
-            if crash is not None and crash.phase in ("upload", "repair"):
-                kwargs["crash_at"] = (crash.crash_window,
-                                      crash.at_frame or 0)
-            sessions.append(sim.clients[cid].uplink_session(
-                sim.chunk_elems, server.uplink_endpoint(cid),
-                uri="fl/model/upload", feedback_uri="fl/model/upload/fb",
-                encoding=enc, residual=residual, **kwargs))
+        # each session frames and validates its chunks: scheduler work
+        with obs.span(obs.SCHED_UPLINK):
+            for cid in pending:
+                crash = self.faults.client_crash(cid)
+                kwargs = {"start_at": ready.get(cid, 0.0)}
+                if backoff is not None:
+                    kwargs["max_windows"] = backoff.max_windows
+                if crash is not None and crash.phase in ("upload", "repair"):
+                    kwargs["crash_at"] = (crash.crash_window,
+                                          crash.at_frame or 0)
+                sessions.append(sim.clients[cid].uplink_session(
+                    sim.chunk_elems, server.uplink_endpoint(cid),
+                    uri="fl/model/upload",
+                    feedback_uri="fl/model/upload/fb",
+                    encoding=enc, residual=residual, **kwargs))
         if not sessions:
             sim.last_medium_report = None
             sim.last_uplink_reports = []
@@ -712,10 +747,11 @@ class RoundEngine:
                            .dataset_size())
 
         from repro.fl.chunking import run_interleaved_uplinks
-        report = run_interleaved_uplinks(
-            medium, sessions, record=sim._record_uplink, on_complete=fold,
-            deadline_s=deadline, backoff=backoff, faults=self.faults,
-            legacy=sim.legacy_scheduler)
+        with obs.span(obs.SCHED_UPLINK):
+            report = run_interleaved_uplinks(
+                medium, sessions, record=sim._record_uplink,
+                on_complete=fold, deadline_s=deadline, backoff=backoff,
+                faults=self.faults, legacy=sim.legacy_scheduler)
         resume_cids = []
         for s in sessions:
             cid = s.client_id
@@ -742,18 +778,19 @@ class RoundEngine:
             rkwargs = {}
             if backoff is not None:
                 rkwargs["max_windows"] = backoff.max_windows
-            for cid in resume_cids:
-                self._attr(cid, "crash-resumed")
-                resume_sessions.append(sim.clients[cid].uplink_session(
-                    sim.chunk_elems, server.uplink_endpoint(cid),
-                    uri="fl/model/upload",
-                    feedback_uri="fl/model/upload/fb",
-                    encoding=enc, residual=residual,
-                    start_at=medium.clock, poll_first=True, **rkwargs))
-            report2 = run_interleaved_uplinks(
-                medium, resume_sessions, record=sim._record_uplink,
-                on_complete=fold, deadline_s=deadline, backoff=backoff,
-                faults=self.faults, legacy=sim.legacy_scheduler)
+            with obs.span(obs.SCHED_UPLINK):
+                for cid in resume_cids:
+                    self._attr(cid, "crash-resumed")
+                    resume_sessions.append(sim.clients[cid].uplink_session(
+                        sim.chunk_elems, server.uplink_endpoint(cid),
+                        uri="fl/model/upload",
+                        feedback_uri="fl/model/upload/fb",
+                        encoding=enc, residual=residual,
+                        start_at=medium.clock, poll_first=True, **rkwargs))
+                report2 = run_interleaved_uplinks(
+                    medium, resume_sessions, record=sim._record_uplink,
+                    on_complete=fold, deadline_s=deadline, backoff=backoff,
+                    faults=self.faults, legacy=sim.legacy_scheduler)
             report2.per_client_done_s = {**report.per_client_done_s,
                                          **report2.per_client_done_s}
             # the resumed run re-derives energy over the whole medium
@@ -773,6 +810,8 @@ class RoundEngine:
                 else:
                     self._attr(cid, "crash")
                     dropped.append(cid)
+        if medium is not sim._round_medium:
+            self._count_frames(medium)    # this collection's own medium
         sim.last_medium_report = report
         sim.last_uplink_reports = [s.report
                                    for s in sessions + resume_sessions]

@@ -14,6 +14,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import obs
 from repro.core import cddl, fastpath
 from repro.core.messages import (
     CHUNK_ENCODINGS,
@@ -206,45 +207,49 @@ class FLSimulation:
         """
         if not receivers:
             return []
-        chunks = list(self.server.global_update_chunks(
-            self.chunk_elems, encoding=self.chunk_encoding))
-        if self.residual_uplink:
-            # record the server's copy of the reference the cohort is
-            # about to install: under a lossy chunk encoding the clients
-            # hold the *dequantized* model, and residual folds must
-            # resolve against exactly that vector, not the f32 global
-            flat = self.server.global_params
-            if self.chunk_encoding is ParamsEncoding.TA_F16:
-                self._residual_ref = flat.astype("<f2").astype("<f4")
-            elif self.chunk_encoding is ParamsEncoding.Q8:
-                self._residual_ref = quantize_q8(
-                    flat, Q8_BLOCK)[2].astype("<f4", copy=False)
-            else:
-                self._residual_ref = flat
+        with obs.span(obs.SERVER_ENCODE):
+            chunks = list(self.server.global_update_chunks(
+                self.chunk_elems, encoding=self.chunk_encoding))
+            if self.residual_uplink:
+                # record the server's copy of the reference the cohort is
+                # about to install: under a lossy chunk encoding the
+                # clients hold the *dequantized* model, and residual folds
+                # must resolve against exactly that vector, not the f32
+                # global
+                flat = self.server.global_params
+                if self.chunk_encoding is ParamsEncoding.TA_F16:
+                    self._residual_ref = flat.astype("<f2").astype("<f4")
+                elif self.chunk_encoding is ParamsEncoding.Q8:
+                    self._residual_ref = quantize_q8(
+                        flat, Q8_BLOCK)[2].astype("<f4", copy=False)
+                else:
+                    self._residual_ref = flat
         self._downlink_crashed = set()
         self._downlink_resumed = set()
         if self.downlink_mode == "medium" and self._round_medium is not None:
             medium = self._round_medium
-            report = run_medium_downlink(
-                medium, chunks, [self.clients[cid] for cid in receivers],
-                uri="fl/model/chunk", feedback_uri="fl/model/chunk/fb",
-                record=self.accounting.record,
-                backoff=(self.round_policy.backoff
-                         if self.round_policy else None),
-                client_ids=receivers, faults=self.faults,
-                checkpoint=self._client_checkpoint,
-                on_crash=self._client_crash_cb,
-                resume_client=self.restart_client)
+            with obs.span(obs.SCHED_DOWNLINK):
+                report = run_medium_downlink(
+                    medium, chunks, [self.clients[cid] for cid in receivers],
+                    uri="fl/model/chunk", feedback_uri="fl/model/chunk/fb",
+                    record=self.accounting.record,
+                    backoff=(self.round_policy.backoff
+                             if self.round_policy else None),
+                    client_ids=receivers, faults=self.faults,
+                    checkpoint=self._client_checkpoint,
+                    on_crash=self._client_crash_cb,
+                    resume_client=self.restart_client)
             self.last_downlink_report = report
             self._publish_downlink_report(medium)
             # the rest of the round continues on the same clock axis
             self.link.advance_to_round(medium.clock)
             return [receivers[i] for i in report.completed]
-        report = run_selective_repeat(
-            self.link, chunks, [self.clients[cid] for cid in receivers],
-            uri="fl/model/chunk", feedback_uri="fl/model/chunk/fb",
-            multicast=True, record=self.accounting.record,
-            client_ids=receivers)
+        with obs.span(obs.SCHED_DOWNLINK):
+            report = run_selective_repeat(
+                self.link, chunks, [self.clients[cid] for cid in receivers],
+                uri="fl/model/chunk", feedback_uri="fl/model/chunk/fb",
+                multicast=True, record=self.accounting.record,
+                client_ids=receivers)
         self.last_downlink_report = report
         return [receivers[i] for i in report.completed]
 
@@ -343,8 +348,9 @@ class FLSimulation:
                 # transfer on the round clock, decoded from its ring
                 busy0 = medium.busy_s
                 ring = BlockReceiveRing()
-                ok, stats = medium.transmit_payload(
-                    payload, uri="fl/model", code=Code.POST, ring=ring)
+                with obs.span(obs.SCHED_DOWNLINK):
+                    ok, stats = medium.transmit_payload(
+                        payload, uri="fl/model", code=Code.POST, ring=ring)
                 self.accounting.record("FL_Global_Model_Update", stats)
                 medium.downlink_airtime_s = medium.clock
                 medium.downlink_busy_s = medium.busy_s - busy0
@@ -352,20 +358,17 @@ class FLSimulation:
                 self.link.advance_to_round(medium.clock)
                 if not ok:
                     return [], list(selected)
-                for cid in selected:
-                    self.clients[cid].handle_global_model(
-                        FLGlobalModelUpdate.from_cbor_segments(ring))
+                self._install(selected, ring)
                 return list(selected), []
             # one wire transfer reaches everyone; every client decodes
             # the same delivered ring (its arena is the receiver-side
             # owned copy, decoded as views)
-            ring = self._send(payload, "FL_Global_Model_Update",
-                              "fl/model", Code.POST, validated=True)
+            with obs.span(obs.SCHED_DOWNLINK):
+                ring = self._send(payload, "FL_Global_Model_Update",
+                                  "fl/model", Code.POST, validated=True)
             if ring is None:
                 return [], list(selected)
-            for cid in selected:
-                self.clients[cid].handle_global_model(
-                    FLGlobalModelUpdate.from_cbor_segments(ring))
+            self._install(selected, ring)
             return list(selected), []
         # unicast: deliver + decode per client so only ONE ring is alive
         # at a time (N simultaneous arenas would put peak memory back at
@@ -373,21 +376,21 @@ class FLSimulation:
         receivers, dropped = [], []
         busy0 = medium.busy_s if medium is not None else 0.0
         for cid in selected:
-            if medium is not None:
-                ring = BlockReceiveRing()
-                ok, stats = medium.transmit_payload(
-                    payload, uri="fl/model", code=Code.POST, ring=ring)
-                self.accounting.record("FL_Global_Model_Update", stats)
-                if not ok:
-                    ring = None
-            else:
-                ring = self._send(payload, "FL_Global_Model_Update",
-                                  "fl/model", Code.POST, validated=True)
+            with obs.span(obs.SCHED_DOWNLINK):
+                if medium is not None:
+                    ring = BlockReceiveRing()
+                    ok, stats = medium.transmit_payload(
+                        payload, uri="fl/model", code=Code.POST, ring=ring)
+                    self.accounting.record("FL_Global_Model_Update", stats)
+                    if not ok:
+                        ring = None
+                else:
+                    ring = self._send(payload, "FL_Global_Model_Update",
+                                      "fl/model", Code.POST, validated=True)
             if ring is None:
                 dropped.append(cid)
                 continue
-            self.clients[cid].handle_global_model(
-                FLGlobalModelUpdate.from_cbor_segments(ring))
+            self._install([cid], ring)
             receivers.append(cid)
         if medium is not None:
             medium.downlink_airtime_s = medium.clock
@@ -395,6 +398,14 @@ class FLSimulation:
             self._publish_downlink_report(medium)
             self.link.advance_to_round(medium.clock)
         return receivers, dropped
+
+    def _install(self, cids: list[int], ring) -> None:
+        """Each of ``cids`` decodes the delivered monolithic global from
+        ``ring`` and installs it."""
+        with obs.span(obs.ASSEMBLE):
+            for cid in cids:
+                self.clients[cid].handle_global_model(
+                    FLGlobalModelUpdate.from_cbor_segments(ring))
 
     def _publish_downlink_report(self, medium) -> None:
         """Downlink-only medium accounting, published right after the
