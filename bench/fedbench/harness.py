@@ -2,9 +2,15 @@
 optional trace, the comparison with the reference, and the result line.
 
 The window is whole jobs: it opens at a job boundary after set-up and
-closes at the end of the first job that ends at or after ``seconds``, so
-``round_s`` and ``updates_per_s`` are all the work over all the time of
-whole fresh federations.
+closes at the end of the first job that ends at or after ``seconds``, and
+never before the traffic's ``tally_jobs`` jobs have ended.  ``round_s`` and
+``updates_per_s`` are all the work over all the time of whole fresh
+federations.  The result line's ``attempted`` and ``failed`` count the
+window's first ``tally_jobs`` jobs only: job ``j`` draws its selection,
+dropouts and medium from ``(seed, j)``, so at one seed those are the same
+jobs at any speed, and a faster program is not judged on the seeded
+failures of the extra jobs it fits in.  The ``window`` note keeps the
+whole window's counts as ``attempted_window`` and ``failed_window``.
 """
 from __future__ import annotations
 
@@ -35,13 +41,18 @@ class Window:
     t0: float
     t1: float
     results: list       # RoundResult per round, in order
-    jobs: int
+    job_ends: list      # len(results) at the end of each job
+
+    @property
+    def jobs(self) -> int:
+        return len(self.job_ends)
 
 
 def run_window(dep: Deployment, seconds: float, first_job: int,
                before_job=None) -> Window:
     rounds = dep.traffic["rounds_per_job"]
-    results, job = [], first_job
+    tally = dep.traffic["tally_jobs"]
+    results, job_ends, job = [], [], first_job
     t0 = time.perf_counter()
     while True:
         sim = dep.new_job(job)
@@ -50,21 +61,33 @@ def run_window(dep: Deployment, seconds: float, first_job: int,
         for _ in range(rounds):
             results.append(sim.run_round())
         job += 1
-        if time.perf_counter() - t0 >= seconds:
+        job_ends.append(len(results))
+        if (len(job_ends) >= tally
+                and time.perf_counter() - t0 >= seconds):
             break
-    return Window(t0, time.perf_counter(), results, job - first_job)
+    return Window(t0, time.perf_counter(), results, job_ends)
 
 
-def window_stats(results: list, wall_s: float) -> dict:
-    """End-to-end numbers over a window's rounds and its wall time."""
-    folded = sum(len(r.reporters) for r in results if probes.installed(r))
-    attempted = sum(len(r.participants) for r in results)
+def _attempted_folded(results: list) -> tuple[int, int]:
+    return (sum(len(r.participants) for r in results),
+            sum(len(r.reporters) for r in results if probes.installed(r)))
+
+
+def window_stats(win: Window, tally_jobs: int) -> dict:
+    """End-to-end numbers over a window's rounds and its wall time;
+    ``attempted`` and ``failed`` over its first ``tally_jobs`` jobs."""
+    results, wall_s = win.results, win.t1 - win.t0
+    attempted, folded = _attempted_folded(results)
+    tally_attempted, tally_folded = _attempted_folded(
+        results[:win.job_ends[tally_jobs - 1]])
     return {
         "rounds": len(results),
         "round_s": wall_s / len(results),
         "updates_per_s": folded / wall_s,
-        "attempted": attempted,
-        "failed": attempted - folded,
+        "attempted": tally_attempted,
+        "failed": tally_attempted - tally_folded,
+        "attempted_window": attempted,
+        "failed_window": attempted - folded,
         "folded": folded,
         "missed_quorum": sum(not probes.installed(r) for r in results),
         "dropped": sum(len(r.dropped) for r in results),
@@ -150,10 +173,11 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     setup_s = time.perf_counter() - t_start
     win = run_window(dep, seconds, 1, before_job)
     wall = win.t1 - win.t0
-    stats = window_stats(win.results, wall)
+    stats = window_stats(win, cell.traffic["tally_jobs"])
     in_window = monitor.snapshot()
     emit(phase="set_up", setup_s=setup_s, **set_up)
     emit(phase="window", wall_s=wall, jobs=win.jobs,
+         tally_jobs=cell.traffic["tally_jobs"],
          compiles_in_window=in_window["compiles"] - set_up["compiles"],
          cache_writes_in_window=(in_window["cache_writes"]
                                  - set_up["cache_writes"]), **stats)

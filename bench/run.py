@@ -7,18 +7,22 @@ One process, which holds the chip and starts no child.  It builds the
 cell's federated deployment from the seed (``bench/configs``,
 ``bench/traffic``), runs every client's local training once and a whole
 checked job (the set-up), then measures whole jobs of
-``FLSimulation.run_round`` for at least ``--seconds``.  With ``--trace 1``
-it wraps the layers in host spans, takes a profiler trace of the window's
-first job, and reports the per-layer metrics of ``BENCHMARK.json`` instead
-of the end-to-end ones.  After the window it compares the checked job with
-the plain reference (``bench/fedbench/reference.py``).
+``FLSimulation.run_round`` for at least ``--seconds`` and at least the
+traffic's ``tally_jobs`` jobs.  With ``--trace 1`` it wraps the layers in
+host spans, takes a profiler trace of the window's first job, and reports
+the per-layer metrics of ``BENCHMARK.json`` instead of the end-to-end ones.
+After the window it compares the checked job with the plain reference
+(``bench/fedbench/reference.py``).
 
 Earlier stdout lines are JSON notes (set-up compiles, compiles inside the
-window, rounds, reporters, stragglers, dropouts, virtual airtime).  The
-last stdout line is the result: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device``, with ``--trace 1`` also ``breakdown``, and last
-``checks``, each compared number beside its limit; the same numbers are
-the last lines on stderr.  The exit code is non-zero, with no result,
+window, jobs, rounds, reporters, stragglers, dropouts, virtual airtime,
+and the whole window's ``attempted_window`` and ``failed_window``).  The
+last stdout line is the result: ``correct``, ``attempted`` and ``failed``
+(client updates selected, and those not folded into an installed global,
+over the window's first ``tally_jobs`` jobs: the same jobs at every
+speed), ``metrics``, ``device``, with ``--trace 1`` also ``breakdown``, and
+last ``checks``, each compared number beside its limit; the same numbers
+are the last lines on stderr.  The exit code is non-zero, with no result,
 when JAX finds no TPU, fewer chips than the cell asks for, or no program
 sources beside ``bench/``.
 

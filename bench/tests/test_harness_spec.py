@@ -48,6 +48,18 @@ def test_workload_cell_loads(wl):
     assert cell.per_layer and set(cell.limits) >= {"fold_mismatch"}
 
 
+@pytest.mark.parametrize("path", sorted((BENCH / "traffic").glob("*.json")),
+                         ids=lambda p: p.stem)
+def test_traffic_file_tallies_whole_jobs(path):
+    """Every mix names the jobs its ``attempted`` and ``failed`` count:
+    a whole number, at least 2 fresh jobs after the checked one."""
+    traffic = json.loads(path.read_text())
+    assert traffic["name"] == path.stem
+    tally = traffic["tally_jobs"]
+    assert isinstance(tally, int) and not isinstance(tally, bool)
+    assert tally >= 2
+
+
 @pytest.mark.parametrize("metric", BENCHMARK["per_layer"],
                          ids=lambda m: m["name"])
 def test_per_layer_reader_loads(metric):
@@ -77,7 +89,8 @@ def test_new_files_are_found_by_name(tmp_path):
     (tmp_path / "bench/configs/lenet5-new.json").write_text(json.dumps(cfg))
     (tmp_path / "bench/traffic/new-mix.json").write_text(json.dumps(
         {"name": "new-mix", "chunk_encoding": "ta-float32le",
-         "residual_uplink": False, "rounds_per_job": 2, "frame_loss": 0.1}))
+         "residual_uplink": False, "rounds_per_job": 2, "tally_jobs": 2,
+         "frame_loss": 0.1}))
     (tmp_path / "bench/checks/new-cell.json").write_text(
         (ROOT / "bench/checks/paper-f32.json").read_text())
     (tmp_path / "bench/metrics/new_metric.py").write_text(

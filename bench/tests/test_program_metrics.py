@@ -121,8 +121,10 @@ def test_traced_cohort_run_reports_the_seven_metrics(monkeypatch):
     assert result["correct"], result["checks"]
     metrics = result["metrics"]
     assert set(METRICS) <= set(metrics)
-    # the window's rounds after the traced one (the reference runs none)
-    rounds = obs.history()[-2:]
+    # the window's rounds after the traced one: at 0.01 s the window is
+    # the tallied jobs (the reference runs none)
+    window = cell.traffic["tally_jobs"] * cell.traffic["rounds_per_job"]
+    rounds = obs.history()[-(window - 1):]
     trained = [r["spans"][obs.CLIENT_TRAIN]["calls"] for r in rounds]
     assert all(trained)
     one = per_client_counts(cell.config)
