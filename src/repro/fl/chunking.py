@@ -924,7 +924,8 @@ def run_medium_downlink(
                     ring = rings[r].get(i)
                     if ring is None:
                         ring = rings[r][i] = BlockReceiveRing()
-                    ring.feed(fr.msg)
+                    ring.add_block(fr.payload, num=fr.block_num,
+                                   last=not fr.more)
                     if not ring.complete:
                         continue
                     del rings[r][i]
@@ -1162,7 +1163,8 @@ def _deliver(by_client: dict[int, UplinkSession], frame,
     ring = sess.rings.get(frame.chunk_index)
     if ring is None:
         ring = sess.rings[frame.chunk_index] = BlockReceiveRing()
-    ring.feed(frame.msg)             # slots by Block1 NUM; dups dropped
+    # slots by Block1 NUM; dups dropped
+    ring.add_block(frame.payload, num=frame.block_num, last=not frame.more)
     if not ring.complete:
         return                       # gap: wait for repair to fill it
     del sess.rings[frame.chunk_index]   # a garbage arena is dropped whole

@@ -535,10 +535,14 @@ class RoundEngine:
 
     @staticmethod
     def _count_frames(medium) -> None:
-        """Copy a round medium's frame counts into the round's record."""
+        """Copy a round medium's frame counts into the round's record.
+        ``frames_built`` (frames whose full CoAP message was built, counted
+        as they are) is put beside them, at 0 when every frame took the
+        arithmetic path."""
         if medium is not None:
             obs.count("frames_sent", medium.frames_sent)
             obs.count("frames_lost", medium.frames_lost)
+            obs.count("frames_built", 0)
 
     # -- folding (shared by every uplink mode) -------------------------------
 

@@ -129,41 +129,89 @@ def block_option_value(num: int, more: bool, szx: int) -> bytes:
     return v.to_bytes(length, "big")
 
 
-def szx_for(block_size: int) -> int:
-    return int(math.log2(block_size)) - 4
+# every blockwise frame carries 64 B blocks (SZX 2: 2**(SZX + 4) bytes)
+# under one 1-byte token
+BLOCK_SZX = COAP_MAX_PAYLOAD.bit_length() - 5
+BLOCK_TOKEN = b"\x01"
 
 
-def iter_blockwise_messages(payload, *, uri: str, code: Code = Code.POST,
-                            block_size: int = COAP_MAX_PAYLOAD,
-                            mid0: int = 0, token: bytes = b"\x01"):
-    """Lazily split a payload into Block1 CoAP messages fitting the MTU.
+class BlockwiseFraming:
+    """Header template of one blockwise transfer: what every Block1 frame
+    of a URI and code shares, worked out once, so that a frame's encoded
+    length is arithmetic on its NUM, M bit and block length.
 
-    ``payload`` is anything with ``len()`` and contiguous slicing —
-    ``bytes``, a buffer, or a ``ScatterPayload`` over vectored segments.
-    One block exists at a time: a multi-MB vectored payload is sliced
-    ≤``block_size`` per step and never joined, so the wire path costs
-    O(block) transient memory."""
-    szx = szx_for(block_size)
-    path_opts = [(Option.URI_PATH, seg.encode())
-                 for seg in uri.strip("/").split("/")]
-    fmt_opt = (Option.CONTENT_FORMAT, bytes([CONTENT_CBOR]))  # copy-ok: 1-byte content-format constant, not a buffer copy
-    n_blocks = max(1, math.ceil(len(payload) / block_size))
-    for i in range(n_blocks):
-        chunk = payload[i * block_size:(i + 1) * block_size]
-        more = i < n_blocks - 1
-        opts = list(path_opts) + [fmt_opt]
-        if n_blocks > 1:
-            opts.append((Option.BLOCK1, block_option_value(i, more, szx)))
-        yield CoapMessage(Type.CON, code, mid0 + i, token, opts, chunk)
+    A frame is the 4-byte CoAP header and token, the URI-Path and
+    Content-Format options (the same for every block), the Block1 option
+    (absent from a single-block transfer), then the ``0xFF`` marker and
+    the block.  ``message`` builds the full ``CoapMessage`` for a caller
+    that needs one; ``blocks`` slices and measures, building nothing."""
+
+    __slots__ = ("code", "_head", "_base")
+
+    def __init__(self, *, uri: str, code: Code = Code.POST) -> None:
+        self.code = code
+        self._head = tuple(
+            [(Option.URI_PATH, seg.encode())
+             for seg in uri.strip("/").split("/")]
+            + [(Option.CONTENT_FORMAT, bytes([CONTENT_CBOR]))])  # copy-ok: 1-byte content-format constant, not a buffer copy
+        self._base = len(CoapMessage(Type.CON, code, 0, BLOCK_TOKEN,
+                                     list(self._head)).encode())
+
+    def wire_bytes(self, num: int, more: bool, nbytes: int) -> int:
+        """Encoded length of block ``num`` carrying ``nbytes``.  Block1
+        follows Content-Format (12 -> 27): a delta of 15 always takes the
+        one-byte extension, so the option is 2 bytes plus its 1-3 byte
+        value (1 below NUM 16, 2 below 4096, 3 above)."""
+        n = self._base
+        if num or more:
+            n += 2 + (((num << 4) | (more << 3) | BLOCK_SZX).bit_length()
+                      + 7) // 8
+        if nbytes:
+            n += 1 + nbytes
+        return n
+
+    def message(self, block, num: int, more: bool) -> CoapMessage:
+        """Block ``num``'s full CON message, as sent on the air."""
+        opts = list(self._head)
+        if num or more:
+            opts.append((Option.BLOCK1, block_option_value(num, more,
+                                                           BLOCK_SZX)))
+        return CoapMessage(Type.CON, self.code, num, BLOCK_TOKEN, opts,
+                           block)
+
+    def blocks(self, payload):
+        """Yield ``(num, more, block, wire_bytes)`` for every frame of
+        ``payload`` in order, one block at a time.
+
+        ``payload`` is anything with ``len()`` and contiguous slicing —
+        ``bytes``, a buffer, or a ``ScatterPayload`` over vectored
+        segments: a multi-MB vectored payload is sliced ≤64 B per step and
+        never joined.  A frame over the 127 B MTU raises."""
+        size = len(payload)
+        last = max(1, -(-size // COAP_MAX_PAYLOAD)) - 1
+        wire_bytes = self.wire_bytes
+        for num in range(last + 1):
+            lo = num * COAP_MAX_PAYLOAD
+            more = num < last
+            wire = wire_bytes(num, more, min(COAP_MAX_PAYLOAD, size - lo))
+            if wire + LOWPAN_OVERHEAD > IEEE802154_MTU:
+                raise AssertionError(
+                    f"CoAP message exceeds MTU: {wire + LOWPAN_OVERHEAD}")
+            yield num, more, payload[lo:lo + COAP_MAX_PAYLOAD], wire
 
 
-def blockwise_messages(payload, *, uri: str, code: Code = Code.POST,
-                       block_size: int = COAP_MAX_PAYLOAD,
-                       mid0: int = 0, token: bytes = b"\x01") -> list[CoapMessage]:
+def iter_blockwise_messages(payload, *, uri: str, code: Code = Code.POST):
+    """Lazily split a payload into the Block1 CoAP messages that carry it
+    (``BlockwiseFraming.blocks``, each built into its message)."""
+    framing = BlockwiseFraming(uri=uri, code=code)
+    for num, more, block, _ in framing.blocks(payload):
+        yield framing.message(block, num, more)
+
+
+def blockwise_messages(payload, *, uri: str,
+                       code: Code = Code.POST) -> list[CoapMessage]:
     """Eager form of ``iter_blockwise_messages`` (materializes the list)."""
-    return list(iter_blockwise_messages(payload, uri=uri, code=code,
-                                        block_size=block_size, mid0=mid0,
-                                        token=token))
+    return list(iter_blockwise_messages(payload, uri=uri, code=code))
 
 
 # RFC 7959 §2.2: the block NUM field is at most 20 bits wide.  A frame
@@ -392,17 +440,12 @@ class TransferStats:
             setattr(self, f, getattr(self, f) + getattr(other, f))
 
 
-def transfer_stats(payload: bytes, *, uri: str,
+def transfer_stats(payload, *, uri: str,
                    code: Code = Code.POST) -> TransferStats:
     """Frame accounting for one application payload over the 127 B link."""
-    msgs = blockwise_messages(payload, uri=uri, code=code)
-    stats = TransferStats(messages=1, blocks=len(msgs),
-                          payload_bytes=len(payload))
-    for m in msgs:
-        wire = len(m.encode())
-        if wire + LOWPAN_OVERHEAD > IEEE802154_MTU:
-            raise AssertionError(
-                f"CoAP message exceeds MTU: {wire + LOWPAN_OVERHEAD}")
+    stats = TransferStats(messages=1, payload_bytes=len(payload))
+    for _, _, _, wire in BlockwiseFraming(uri=uri, code=code).blocks(payload):
+        stats.blocks += 1
         stats.frames += 1
         stats.wire_bytes += wire
         stats.link_bytes += wire + LOWPAN_OVERHEAD

@@ -30,7 +30,7 @@ clock/busy/idle.  The selective-repeat scheduling on top lives in
 from __future__ import annotations
 
 import heapq
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Callable, Sequence
 
 import numpy as np
@@ -49,8 +49,10 @@ def _damage_frame(frame: TaggedFrame, kind: str) -> TaggedFrame | None:
     (``"corrupt"``) or the final payload byte lost (``"truncate"``).
     The receive path must detect it (CBOR decode / per-chunk CRC) and
     recover via NACK — never crash, never install garbage.  Returns None
-    when there is no payload left to damage (degrades to a drop)."""
-    payload = bytes(frame.msg.payload or b"")  # copy-ok: fault injection mutates an owned copy by design
+    when there is no payload left to damage (degrades to a drop).  Only a
+    ``FaultPlan`` verdict takes this path, so the damaged copy carries its
+    full message."""
+    payload = bytes(frame.payload)  # copy-ok: fault injection mutates an owned copy by design
     if not payload:
         return None
     if kind == "corrupt":
@@ -64,7 +66,7 @@ def _damage_frame(frame: TaggedFrame, kind: str) -> TaggedFrame | None:
             return None
     else:
         raise ValueError(f"unknown frame damage kind {kind!r}")
-    return replace(frame, msg=replace(frame.msg, payload=payload))
+    return frame.with_payload(payload)
 
 
 @dataclass

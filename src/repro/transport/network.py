@@ -29,19 +29,19 @@ Two delivery models coexist (docs/chunk_protocol.md):
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Callable, Iterable, Iterator, Sequence
 
 import numpy as np
 
 from repro.core.fastpath import ScatterPayload
 from repro.transport.coap import (
-    IEEE802154_MTU,
     LOWPAN_OVERHEAD,
     BlockReceiveRing,
+    BlockwiseFraming,
+    CoapMessage,
     Code,
     TransferStats,
-    iter_blockwise_messages,
 )
 
 LINK_BPS = 250_000
@@ -76,10 +76,9 @@ def con_blockwise_transfer(payload, *, uri: str, code: Code,
     undelivered (``failed_messages`` = 1) and aborts the transfer."""
     payload = as_wire_payload(payload)
     stats = TransferStats(messages=1, payload_bytes=len(payload))
-    for msg in iter_blockwise_messages(payload, uri=uri, code=code):
-        wire = len(msg.encode())
+    for num, more, block, wire in BlockwiseFraming(
+            uri=uri, code=code).blocks(payload):
         frame = wire + LOWPAN_OVERHEAD
-        assert frame <= IEEE802154_MTU, frame
         stats.blocks += 1
         attempts = 0
         while True:
@@ -96,7 +95,7 @@ def con_blockwise_transfer(payload, *, uri: str, code: Code,
                 return stats
             stats.retransmissions += 1
         if ring is not None:
-            ring.feed(msg)
+            ring.add_block(block, num=num, last=not more)
     return stats
 
 
@@ -108,23 +107,55 @@ class StreamDelivery:
     delivered: list[set[int]]    # per receiver: chunk indices that arrived
 
 
-@dataclass(frozen=True)
 class TaggedFrame:
     """One link frame of a multiplexed chunk window.
 
     The tag (client, window, chunk_index, block_num) is what lets frames
     from many concurrent uplinks share one contention domain: the medium
     arbitrates and reorders *frames*, and the receive side routes each one
-    to the right client's per-chunk reorder-aware ring by its tag — the
-    Block1 NUM inside ``msg`` slots it into the arena.
+    to the right client's per-chunk reorder-aware ring by its tag, slotting
+    ``payload`` into the arena by ``block_num`` (``more`` clear on the
+    final block).  ``wire_bytes`` is the encoded CoAP size (MAC/6LoWPAN
+    overhead extra), computed by ``BlockwiseFraming``.
+
+    ``msg`` is the full CoAP message, built from ``framing`` only when a
+    caller reads it: it encodes to exactly ``wire_bytes`` bytes.  Each
+    build counts as ``frames_built`` in the round's record.
     """
 
-    client: int
-    window: int
-    chunk_index: int
-    block_num: int
-    msg: CoapMessage
-    wire_bytes: int          # encoded CoAP size (MAC/6LoWPAN overhead extra)
+    __slots__ = ("client", "window", "chunk_index", "block_num", "_msg",
+                 "wire_bytes", "payload", "more", "framing")
+
+    def __init__(self, client: int, window: int, chunk_index: int,
+                 block_num: int, msg: CoapMessage | None = None,
+                 wire_bytes: int = 0, payload=b"", more: bool = False,
+                 framing: BlockwiseFraming | None = None) -> None:
+        self.client = client
+        self.window = window
+        self.chunk_index = chunk_index
+        self.block_num = block_num
+        self._msg = msg
+        self.wire_bytes = wire_bytes
+        self.payload = payload
+        self.more = more
+        self.framing = framing
+
+    @property
+    def msg(self) -> CoapMessage:
+        if self._msg is None:
+            # off the fast path; imported here so transport stays jax-free
+            from repro import obs
+            obs.count("frames_built", 1)
+            self._msg = self.framing.message(self.payload, self.block_num,
+                                             self.more)
+        return self._msg
+
+    def with_payload(self, payload) -> "TaggedFrame":
+        """This frame carrying ``payload`` instead (a damaged copy): same
+        tag and ``wire_bytes``, its message built with the new payload."""
+        return TaggedFrame(self.client, self.window, self.chunk_index,
+                           self.block_num, replace(self.msg, payload=payload),
+                           self.wire_bytes, payload, self.more, self.framing)
 
 
 # The ``client`` tag of a downlink (server -> cohort) frame.  Downlink
@@ -155,16 +186,14 @@ def iter_tagged_frames(payloads: Sequence, *, uri: str, client: int,
     a time — a repair window over a multi-MB model costs O(block)
     transient memory, exactly like the inline ``request_stream`` path.
     """
-    payloads = [as_wire_payload(p) for p in payloads]
+    framing = BlockwiseFraming(uri=uri, code=code)
     if indices is None:
         indices = range(len(payloads))
     for payload, idx in zip(payloads, indices):
-        for num, msg in enumerate(
-                iter_blockwise_messages(payload, uri=uri, code=code)):
-            wire = len(msg.encode())
-            assert wire + LOWPAN_OVERHEAD <= IEEE802154_MTU, wire
-            yield TaggedFrame(client=client, window=window, chunk_index=idx,
-                              block_num=num, msg=msg, wire_bytes=wire)
+        for num, more, block, wire in framing.blocks(
+                as_wire_payload(payload)):
+            yield TaggedFrame(client, window, idx, num, None, wire, block,
+                              more, framing)
 
 
 @dataclass
@@ -346,9 +375,8 @@ class LossyLink:
                            code: Code) -> TransferStats:
         """Byte/frame accounting for a payload framed once (no retries)."""
         stats = TransferStats(messages=1, payload_bytes=len(payload))
-        for msg in iter_blockwise_messages(payload, uri=uri, code=code):
-            wire = len(msg.encode())
-            assert wire + LOWPAN_OVERHEAD <= IEEE802154_MTU
+        for _, _, _, wire in BlockwiseFraming(uri=uri,
+                                              code=code).blocks(payload):
             stats.blocks += 1
             stats.frames += 1
             stats.wire_bytes += wire

@@ -36,7 +36,8 @@ def ticks(times):
     return lambda: next(it)
 
 
-def q8_sim(clients: int = 4, rows: int = 80, seed: int = 0) -> FLSimulation:
+def q8_sim(clients: int = 4, rows: int = 80, seed: int = 0,
+           faults=None) -> FLSimulation:
     """A chunked q8 residual federation: downlink and interleaved uplinks
     on one shared medium."""
     flat, spec = flatten_params(lenet5.init_params(jax.random.PRNGKey(seed)))
@@ -51,7 +52,8 @@ def q8_sim(clients: int = 4, rows: int = 80, seed: int = 0) -> FLSimulation:
         num_rounds=3, min_local_samples=32, seed=seed), flat)
     return FLSimulation(server, fl_clients, seed=seed, chunk_elems=4096,
                         chunk_encoding="q8-block", residual_uplink=True,
-                        uplink_mode="interleaved", downlink_mode="medium")
+                        uplink_mode="interleaved", downlink_mode="medium",
+                        faults=faults)
 
 
 def test_program_span_names_are_not_the_benchmarks():
@@ -162,6 +164,35 @@ def test_a_chunked_q8_round_records_every_program_span():
     assert c["h2d_transfers"] == 4 * (10 + 2 * 2 + 2 * 2)
     assert c["d2h_transfers"] == 4 * (2 + 10)
     assert c["d2h_bytes"] == 4 * (2 * 4 + 4 * lenet5.PARAM_COUNT)
+
+
+@pytest.mark.parametrize("damage", [False, True],
+                         ids=["clean", "frame-faults"])
+def test_frames_are_built_only_when_damaged(monkeypatch, damage):
+    """``frames_built`` sits beside ``frames_sent``: 0 on a clean medium
+    round, one a damaged frame under a ``FaultPlan``."""
+    from repro.fl.faults import FaultPlan, FrameFault
+    from repro.transport import medium as medium_mod
+
+    damaged = []
+    real = medium_mod._damage_frame
+
+    def damage_frame(frame, kind):
+        out = real(frame, kind)
+        if out is not None:
+            damaged.append(frame)
+        return out
+
+    monkeypatch.setattr(medium_mod, "_damage_frame", damage_frame)
+    plan = FaultPlan(frame_faults=[
+        FrameFault("corrupt", client=1, window=0, chunk_index=0)])
+    sim = q8_sim(clients=3, rows=48, faults=plan if damage else None)
+    sim.run_round()
+    (rec,) = obs.history()
+    c = rec["counters"]
+    assert c["frames_sent"] > 0
+    assert bool(damaged) == damage
+    assert c["frames_built"] == len(damaged)
 
 
 def test_the_clock_never_reaches_a_rounds_results(monkeypatch):
