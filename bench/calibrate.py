@@ -33,22 +33,20 @@ def readings_for(cell, seed: int, faults=(), control: bool = True):
     then each fault."""
     import jax.numpy as jnp
 
-    from fedbench import check, spec
+    from fedbench import check
     from fedbench import faults as planted_faults
     from fedbench.deploy import Deployment
     from fedbench.harness import checked_job
     from fedbench.reference import JobReference
 
-    model = spec.load_reference(cell.config["model"])
-    dep = Deployment(cell.config, cell.traffic, seed, model)
-    args = (model, cell.config, cell.traffic, seed, dep.plans, dep.images,
-            dep.labels)
-    reference = JobReference(*args)
+    dep = Deployment(cell, seed)
+    model = dep.model
+    reference = JobReference(dep)
     g0, record = checked_job(dep)
     ref = reference.run(g0, record)
     yield "program", check.readings(model, cell.traffic, g0, record, ref)
     if control:
-        ctrl = JobReference(*args, dtype=jnp.bfloat16).run(g0, record)
+        ctrl = JobReference(dep, jnp.bfloat16).run(g0, record)
         yield "control", check.readings(
             model, cell.traffic, g0,
             planted_faults.control_record(ctrl, record), ref)

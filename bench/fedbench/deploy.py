@@ -12,9 +12,16 @@ halted for good) from draining the cohort over a long window.
 Sizes that vary by client (sample counts, straggler factors) are a fixed
 set of quantiles of the config's distributions; the seed only permutes
 which client gets which, so every seed does the same amount of work.
+
+What the clients train is the configuration's (``fedbench.spec``): its
+program model's ``loss_fn``, rows made in bulk by its data generator, the
+reference's ``init`` as the initial global and, where the reference defines
+``frozen``, weights held on the device and bound into every client's loss.
 """
 from __future__ import annotations
 
+import functools
+import importlib
 import math
 import statistics
 from dataclasses import dataclass
@@ -23,6 +30,8 @@ from types import ModuleType
 import jax
 import numpy as np
 from jax.flatten_util import ravel_pytree
+
+from fedbench import spec
 
 
 def u32(*entropy: int) -> int:
@@ -49,17 +58,6 @@ def quantile_lognormal(sigma: float, n: int) -> np.ndarray:
                      for i in range(n)])
 
 
-def synthetic_mnist(n: int, rng: np.random.Generator
-                    ) -> tuple[np.ndarray, np.ndarray]:
-    """Class-conditional 28x28 digits: a fixed random template per class
-    plus noise, learnable within a few rounds.  Made in bulk, float32."""
-    templates = rng.standard_normal((10, 28, 28, 1), dtype=np.float32)
-    labels = rng.integers(0, 10, n).astype(np.int32)
-    noise = rng.standard_normal((n, 28, 28, 1), dtype=np.float32)
-    images = templates[labels] + np.float32(0.8) * noise
-    return images, labels
-
-
 @dataclass
 class ClientPlan:
     """What the benchmark gave one client: its rows of the bulk data."""
@@ -80,30 +78,43 @@ def plan_clients(config: dict, seed: int) -> list[ClientPlan]:
             for a, s, f in zip(starts, sizes, strag)]
 
 
-def make_data(plans: list[ClientPlan], seed: int
-              ) -> tuple[np.ndarray, np.ndarray]:
+def make_data(data: ModuleType, plans: list[ClientPlan], seed: int
+              ) -> dict[str, np.ndarray]:
+    """Every client's rows, in bulk: client ``i`` holds rows
+    ``[plans[i].start, plans[i].start + plans[i].size)`` of each key."""
     total = sum(p.size for p in plans)
-    return synthetic_mnist(total, np.random.default_rng(u32(seed, 2)))
+    rows = data.make(total, np.random.default_rng(u32(seed, 2)))
+    for key, v in rows.items():
+        if len(v) != total:
+            raise ValueError(f"data key {key!r} has {len(v)} rows, not "
+                             f"{total}")
+    return rows
 
 
 class Deployment:
     """The program objects of one run: clients built once, a fresh server
     and simulation per job."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int,
-                 model: ModuleType) -> None:
+    def __init__(self, cell: spec.Cell, seed: int) -> None:
         from repro.core.params_codec import flatten_params
         from repro.fl import FLClient
-        from repro.models import lenet5 as program_model
         from repro.train.optim import SGDConfig
 
-        if config["model"] != "lenet5":
-            raise ValueError(f"the FL path runs LeNet-5, not "
-                             f"{config['model']!r}")
-        self.config, self.traffic, self.seed = config, traffic, seed
-        self.model = model
+        config = cell.config
+        self.config, self.traffic, self.seed = config, cell.traffic, seed
+        self.model = model = spec.load_reference(config["model"],
+                                                 cell.bench_dir)
+        program = importlib.import_module(config["program_model"])
         self.plans = plan_clients(config, seed)
-        self.images, self.labels = make_data(self.plans, seed)
+        self.data = make_data(spec.load_data(config["data"], cell.bench_dir),
+                              self.plans, seed)
+        # weights that are not federated: one jitted call on the device,
+        # bound as a constant of each client's jit (see fedbench.spec)
+        self.frozen = None
+        loss_fn = program.loss_fn
+        if hasattr(model, "frozen"):
+            self.frozen = jax.jit(model.frozen)(jax.random.key(u32(seed, 5)))
+            loss_fn = functools.partial(loss_fn, frozen=self.frozen)
         # the initial global of job j: one jitted call on the device
         self._key = jax.random.key(u32(seed, 3))
         self._init = jax.jit(lambda k: ravel_pytree(model.init(k))[0])
@@ -114,9 +125,9 @@ class Deployment:
         sgd = SGDConfig(lr=config["lr"])
         self.clients = [
             FLClient(client_id=i,
-                     data={"images": self.images[p.start:p.start + p.size],
-                           "labels": self.labels[p.start:p.start + p.size]},
-                     loss_fn=program_model.loss_fn, spec=self.spec,
+                     data={k: v[p.start:p.start + p.size]
+                           for k, v in self.data.items()},
+                     loss_fn=loss_fn, spec=self.spec,
                      local_epochs=config["local_epochs"],
                      batch_size=config["batch_size"],
                      val_fraction=config["val_fraction"], sgd=sgd,

@@ -151,8 +151,8 @@ def trace_first_round(sim, log_dir: str, done: dict) -> None:
 
 def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
              monitor: probes.CompileMonitor, t_start: float) -> dict:
-    model = spec.load_reference(cell.config["model"])
-    dep = Deployment(cell.config, cell.traffic, seed, model)
+    dep = Deployment(cell, seed)
+    model = dep.model
     g0, record = checked_job(dep)
     dep.warm_clients(skip={c for r in record for c in r.losses})
     set_up = monitor.snapshot()
@@ -199,7 +199,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
                "peak_flops_per_s": peak_flops(device["kind"]) * len(
                    jax.devices())}
         for m in cell.per_layer:
-            value = spec.load_metric_reader(m["name"]).read(ctx)
+            value = spec.load_metric_reader(m["name"],
+                                            cell.bench_dir).read(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:
@@ -210,12 +211,11 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     log_dir.cleanup()
 
     # the program's state is freed before the reference runs
-    plans, images, labels = dep.plans, dep.images, dep.labels
+    reference = JobReference(dep)
     del dep, win
     gc.collect()
     t_ref = time.perf_counter()
-    ref = JobReference(model, cell.config, cell.traffic, seed, plans, images,
-                       labels).run(g0, record)
+    ref = reference.run(g0, record)
     values = check.readings(model, cell.traffic, g0, record, ref)
     correct, shown = check.judge(values, cell.limits)
     emit(phase="check", reference_s=time.perf_counter() - t_ref)

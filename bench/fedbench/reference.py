@@ -19,12 +19,14 @@ cell's configuration says the rounds produce:
 
 Training runs one jitted call per client (one small program for every
 client: the steps a client lacks are masked), in float32 at ``highest``
-matmul precision, or in bfloat16 for the control.
+matmul precision, or in bfloat16 for the control.  A batch is every key of
+the configuration's data at the same rows; the control casts its floating
+arrays and the frozen weights, and leaves integer arrays (ids, classes) as
+they are.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from types import ModuleType
 
 import jax
 import jax.numpy as jnp
@@ -65,45 +67,59 @@ class RefRound:
 
 
 class JobReference:
-    """Follows one job's rounds; ``dtype`` bfloat16 makes the control."""
+    """Follows one job's rounds; ``dtype`` bfloat16 makes the control.
 
-    def __init__(self, model: ModuleType, config: dict, traffic: dict,
-                 seed: int, plans, images: np.ndarray, labels: np.ndarray,
-                 dtype=jnp.float32) -> None:
-        self.model, self.config, self.traffic = model, config, traffic
-        self.seed, self.plans, self.dtype = seed, plans, dtype
+    Built from the run's ``deploy.Deployment``, of which it keeps what the
+    benchmark made (data, plans, frozen weights) and nothing of the
+    program, so that the program's state can be freed before it runs."""
+
+    def __init__(self, dep, dtype=jnp.float32) -> None:
+        config = dep.config
+        self.model, self.config, self.traffic = dep.model, config, dep.traffic
+        self.seed, self.plans, self.dtype = dep.seed, dep.plans, dtype
+        self.data, self.frozen = dep.data, dep.frozen
         self.batch = config["batch_size"]
-        self.splits = [client_split(p.size, seed, i, config["val_fraction"])
-                       for i, p in enumerate(plans)]
+        self.splits = [client_split(p.size, self.seed, i,
+                                    config["val_fraction"])
+                       for i, p in enumerate(self.plans)]
         self.steps = config["local_epochs"] * max(
             len(tr) // self.batch for tr, _ in self.splits)
-        self.images, self.labels = images, labels
-        shapes = jax.eval_shape(model.init, jax.random.key(0))
+        shapes = jax.eval_shape(self.model.init, jax.random.key(0))
         _, self.unravel = ravel_pytree(jax.tree.map(
             lambda s: np.zeros(s.shape, s.dtype), shapes))
         self._train = jax.jit(self._train_one)
 
     # -- local training ------------------------------------------------------
 
-    def _train_one(self, flat0, xb, yb, on, x_tr, y_tr, w_tr, x_va, y_va,
-                   w_va):
-        """One client: its batches (steps, batch, ...) with a mask of the
-        steps that exist, then its two evaluation sets."""
-        model, dtype, lr = self.model, self.dtype, self.config["lr"]
-        params0 = jax.tree.map(lambda a: a.astype(dtype), self.unravel(flat0))
+    def _cast(self, tree):
+        """The reference's precision on floating arrays only."""
+        return jax.tree.map(
+            lambda a: (a.astype(self.dtype)
+                       if jnp.issubdtype(a.dtype, jnp.floating) else a), tree)
+
+    def _train_one(self, flat0, frozen, batches, on, evals):
+        """One client: its batches (each key ``(steps, batch, ...)``) with a
+        mask of the steps that exist, then its two evaluation sets, each
+        rows and their weights."""
+        model, cast, lr = self.model, self._cast, self.config["lr"]
+        kw = {} if frozen is None else {"frozen": cast(frozen)}
+        params0 = cast(self.unravel(flat0))
 
         def step(p, xs):
-            x, y, keep = xs
-            g = jax.grad(model.loss)(p, x.astype(dtype), y)
+            batch, keep = xs
+            g = jax.grad(model.loss)(p, cast(batch), **kw)
             return jax.tree.map(lambda a, b: jnp.where(keep, a - lr * b, a),
                                 p, g), None
 
-        params, _ = jax.lax.scan(step, params0, (xb, yb, on))
-        tl = model.loss(params, x_tr.astype(dtype), y_tr, w_tr)
-        vl = model.loss(params, x_va.astype(dtype), y_va, w_va)
+        params, _ = jax.lax.scan(step, params0, (batches, on))
+        tl, vl = (model.loss(params, cast(rows), w, **kw)
+                  for rows, w in evals)
         flat = ravel_pytree(jax.tree.map(
             lambda a: a.astype(jnp.float32), params))[0]
         return flat, tl, vl
+
+    def _rows(self, idx: np.ndarray) -> dict[str, np.ndarray]:
+        return {k: v[idx] for k, v in self.data.items()}
 
     def _inputs(self, cid: int, round_: int):
         steps, b = self.steps, self.batch
@@ -119,14 +135,14 @@ class JobReference:
                 bidx[s] = start + train[order[lo:lo + b]]
                 on[s] = True
                 s += 1
-        out = [self.images[bidx], self.labels[bidx], on]
+        evals = []
         for rows in (train[:EVAL_ROWS], val[:EVAL_ROWS]):
             idx = np.zeros(EVAL_ROWS, np.int64)
             idx[:len(rows)] = start + rows
             w = np.zeros(EVAL_ROWS, np.float32)
             w[:len(rows)] = 1.0
-            out += [self.images[idx], self.labels[idx], w]
-        return out
+            evals.append((self._rows(idx), w))
+        return self._rows(bidx), on, tuple(evals)
 
     def train(self, base: np.ndarray, clients: list[int], round_: int
               ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -135,7 +151,8 @@ class JobReference:
         base = jnp.asarray(base)
         with jax.default_matmul_precision("highest"):
             for cid in clients:
-                flat, tl, vl = self._train(base, *self._inputs(cid, round_))
+                flat, tl, vl = self._train(base, self.frozen,
+                                           *self._inputs(cid, round_))
                 flats.append(flat)
                 tls.append(tl)
                 vls.append(vl)

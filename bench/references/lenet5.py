@@ -64,10 +64,12 @@ def forward(params: dict, images: jax.Array) -> jax.Array:
     return x @ params["fc3"]["w"] + params["fc3"]["b"]
 
 
-def loss(params: dict, images: jax.Array, labels: jax.Array,
-         weights: jax.Array | None = None) -> jax.Array:
-    """Mean softmax cross-entropy; ``weights`` masks padded rows."""
-    logits = forward(params, images).astype(jnp.float32)
+def loss(params: dict, batch: dict, weights: jax.Array | None = None
+         ) -> jax.Array:
+    """Mean softmax cross-entropy over ``batch["images"]`` and
+    ``batch["labels"]``; ``weights`` masks padded rows."""
+    labels = batch["labels"]
+    logits = forward(params, batch["images"]).astype(jnp.float32)
     nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
         logits, labels[:, None], axis=-1)[:, 0]
     if weights is None:

@@ -2,6 +2,7 @@
 limits, and a new cell, mix, config or metric is found by name."""
 from __future__ import annotations
 
+import importlib
 import json
 import re
 import shutil
@@ -34,6 +35,8 @@ def test_config_file_loads(cfg):
     assert sorted(data["reduced"]) == sorted(cfg["reduced"])
     assert all(k in data for k in cfg["reduced"])
     spec.load_reference(data["model"])
+    spec.load_data(data["data"])
+    assert callable(importlib.import_module(data["program_model"]).loss_fn)
 
 
 @pytest.mark.parametrize("wl", BENCHMARK["workloads"],
@@ -120,3 +123,5 @@ def test_unknown_names_are_errors():
         spec.load_cell("no-such-cell", ROOT)
     with pytest.raises(FileNotFoundError):
         spec.load_metric_reader("no_such_metric")
+    with pytest.raises(FileNotFoundError):
+        spec.load_data("no-such-data")

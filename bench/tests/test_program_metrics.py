@@ -145,8 +145,7 @@ def test_traced_cohort_run_reports_the_seven_metrics(monkeypatch):
 def test_profiler_trace_holds_each_program_span(tmp_path):
     dep_cell = tiny_cell("cohort-q8")
     from fedbench.deploy import Deployment
-    dep = Deployment(dep_cell.config, dep_cell.traffic, SEED,
-                     spec.load_reference("lenet5"))
+    dep = Deployment(dep_cell, SEED)
     sim = dep.new_job(0)
     sim.run_round()                 # compiles outside the traced round
     sim = dep.new_job(1)
